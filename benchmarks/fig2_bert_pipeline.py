@@ -135,7 +135,7 @@ def measured_rows(steps: int = 4):
     import repro.core.pipeline as pipe
     from repro.configs import get_config
     from repro.core.planner import compile_plan
-    from repro.core.sharding import hybrid_rules
+    from repro.core.sharding import hybrid_rules, make_mesh
     from repro.models.lm import build
     from repro.optim.optimizer import adamw
 
@@ -161,7 +161,7 @@ def measured_rows(steps: int = 4):
 
     rows = []
     # DP
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     plan = compile_plan(model, mesh)
     with mesh:
         params = plan.init_params(jax.random.key(0))
@@ -170,7 +170,7 @@ def measured_rows(steps: int = 4):
         dt = time_fn(lambda: step(params, ost, {"tokens": tokens}, 0))
     rows.append(("whale-dp-measured", n, dt))
     # pipeline (2 stages) × DP
-    mesh2 = jax.make_mesh((2, n // 2, 1), ("stage", "data", "model"))
+    mesh2 = make_mesh((2, n // 2, 1), ("stage", "data", "model"))
     rules = hybrid_rules(mesh2)
     pstep = pipe.make_pipeline_train_step(model, mesh2, rules, opt,
                                           micro_batches=4, donate=False)

@@ -43,8 +43,8 @@ def main():
         n_layers=4, norm="ln", act="gelu", name="bert-like")
     model = build(cfg)
 
-    mesh = jax.make_mesh((stages, data_par, model_par),
-                         ("stage", "data", "model"))
+    mesh = wh.make_mesh((stages, data_par, model_par),
+                        ("stage", "data", "model"))
     rules = wh.hybrid_rules(mesh)
     opt = adamw(lr=1e-3)
 

@@ -41,8 +41,8 @@ print(f"[case 1] out {out.shape}; recorded "
 cfg = get_config("tinyllama-1.1b", smoke=True)
 model = build(cfg)
 n_dev = len(jax.devices())
-mesh = jax.make_mesh((n_dev, 1), ("data", "model")) if n_dev > 1 else \
-    jax.make_mesh((1,), ("data",))
+mesh = wh.make_mesh((n_dev, 1), ("data", "model")) if n_dev > 1 else \
+    wh.make_mesh((1,), ("data",))
 plan = wh.compile_plan(model, mesh)
 
 opt = adamw(lr=1e-3)

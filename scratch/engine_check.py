@@ -18,7 +18,7 @@ model = build(cfg)
 opt = adamw(lr=1e-3)
 
 # ---- 1. GSPMD hybrid plan: dp=4 × tp=2 ----
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = wh.make_mesh((4, 2), ("data", "model"))
 plan = compile_plan(model, mesh)
 params = plan.init_params(jax.random.key(0))
 opt_state = jax.jit(opt.init, out_shardings=wh.core.planner._ns(mesh, plan.opt_specs(opt)) if False else None)(params) if False else opt.init(params)
@@ -46,7 +46,7 @@ with mesh:
 print("serve ok:", logits.shape)
 
 # ---- 3. pipeline: 2 stages × dp=2 × tp=2 ----
-mesh3 = jax.make_mesh((2, 2, 2), ("stage", "data", "model"))
+mesh3 = wh.make_mesh((2, 2, 2), ("stage", "data", "model"))
 rules = wh.hybrid_rules(mesh3)
 plan3 = compile_plan(model, mesh3)
 with mesh3:

@@ -86,12 +86,13 @@ import benchmarks.fig_calibration as fig_cal
 fig_cal.main()
 
 import numpy as np
+import repro as wh
 from repro.core.planner import compile_plan
 from repro.serving.server import Request, Server
 
 _cfg = get_config("tinyllama-1.1b", smoke=True)
 _model = build(_cfg)
-_mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+_mesh = wh.make_mesh((len(jax.devices()),), ("data",))
 _plan = compile_plan(_model, _mesh)
 with _mesh:
     _params = _plan.init_params(jax.random.key(0))

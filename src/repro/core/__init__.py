@@ -29,7 +29,7 @@ from repro.core.planner import (ExecutionPlan, compile_plan,  # noqa: F401
                                 compile_plan_from_cluster, mesh_for_strategy,
                                 rules_for_strategy, strategy_from_taskgraph)
 from repro.core.sharding import (ShardingRules, constrain, hybrid_rules,  # noqa: F401
-                                 use_rules)
+                                 make_mesh, use_rules)
 from repro.core.strategies import (cluster, pipeline, replica, split,  # noqa: F401
                                    stage, sub)
 from repro.core.strategies import auto_parallel as auto_scope  # noqa: F401

@@ -127,6 +127,34 @@ T4_16G = Hardware(
     mxu_eff=0.40,
 )
 
+# Hardware table of each TPU, keyed by ``device_kind`` as jax reports it
+# (a v5e reports "TPU v5 lite").
+TPU_KINDS = {"TPU v5 lite": TPU_V5E}
+
+# short names the CLIs take for a Hardware table (``--hw``)
+HARDWARE_BY_NAME = {"tpu_v5e": TPU_V5E, "v100": V100_PAPER, "p100": P100_16G,
+                    "t4": T4_16G}
+
+
+def device_hardware() -> Hardware:
+    """The Hardware table of the device this process runs on.
+
+    On a TPU it comes from ``jax.devices()[0].device_kind`` through
+    :data:`TPU_KINDS`; a TPU kind with no table raises rather than being
+    planned as some other part.  Off-TPU (CPU test runs on virtual
+    devices) the planner plans for its target part, ``TPU_V5E``.
+    """
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    try:
+        return TPU_KINDS[dev.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no Hardware table for TPU kind {dev.device_kind!r} "
+            f"(known: {sorted(TPU_KINDS)})") from None
+
 
 # ---------------------------------------------------------------------------
 # heterogeneous cluster description (DESIGN.md §2)

@@ -339,9 +339,8 @@ def make_pipeline_loss(model: Model, mesh: Mesh, rules: ShardingRules, *,
     sm_specs = stage_only_specs(model.axes())
 
     def loss_fn(params, tokens):
-        from repro.core.jax_compat import shard_map
         with use_rules(rules):
-            return shard_map(
+            return jax.shard_map(
                 inner, mesh=mesh, in_specs=(sm_specs, P()), out_specs=P(),
                 axis_names=frozenset({"stage"}), check_vma=False,
             )(params, tokens)
@@ -487,9 +486,8 @@ def make_encdec_pipeline_loss(model: Model, mesh: Mesh, rules: ShardingRules,
     sm_specs = jax.tree.map(lambda names: P(), model.axes(), is_leaf=_is_axes)
 
     def loss_fn(params, frames, tokens):
-        from repro.core.jax_compat import shard_map
         with use_rules(rules):
-            return shard_map(
+            return jax.shard_map(
                 inner, mesh=mesh, in_specs=(sm_specs, P(), P()),
                 out_specs=P(), axis_names=frozenset({"stage"}),
                 check_vma=False,

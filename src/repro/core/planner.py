@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.cost_model import StrategySpec
-from repro.core.sharding import ShardingRules, hybrid_rules, use_rules
+from repro.core.sharding import (ShardingRules, hybrid_rules, make_mesh,
+                                 use_rules)
 from repro.core.vdevice import Cluster
 
 
@@ -82,7 +83,7 @@ def mesh_for_strategy(strat: StrategySpec, *, devices=None,
     names.append("data")
     shape.append(strat.model_parallel)   # tp and nested ep share the axis
     names.append("model")
-    return jax.make_mesh(tuple(shape), tuple(names), devices=devices)
+    return make_mesh(shape, names, devices=devices)
 
 
 def rules_for_strategy(mesh: Mesh, strat: StrategySpec) -> ShardingRules:
@@ -317,8 +318,7 @@ class ExecutionPlan:
         rep = NamedSharding(mesh, P())
         if compress_pod and "pod" in mesh.shape:
             # manual over 'pod' only: GSPMD still partitions data/model inside
-            from repro.core.jax_compat import shard_map
-            inner = shard_map(
+            inner = jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(P(), P(), P("pod"), P(), P()),
                 out_specs=(P(), P(), P(), P()),

@@ -24,6 +24,21 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> Mesh:
+    """The one mesh constructor: every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    ``with_sharding_constraint`` refuses the NamedSharding specs
+    :func:`constrain` emits and partially-manual ``shard_map`` bodies see
+    ``(Manual, Explicit, …)`` meshes.  The planner's rules are GSPMD
+    annotations, so every mesh here is built Auto.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 # logical name -> mesh axis name, tuple of axis names, or None (replicated)
 RuleMap = Mapping[str, object]
 
@@ -123,6 +138,15 @@ class ShardingRules:
         )
 
 
+def manual_axis_names() -> frozenset:
+    """Mesh axes currently bound manual (inside shard_map); else empty."""
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or am.empty:
+        return frozenset()
+    return frozenset(a for a, t in zip(am.axis_names, am.axis_types)
+                     if t == jax.sharding.AxisType.Manual)
+
+
 def current_rules() -> ShardingRules | None:
     return getattr(_tls, "rules", None)
 
@@ -149,7 +173,6 @@ def constrain(x: jax.Array, names: Sequence[str | None]) -> jax.Array:
     if rules is None:
         return x
     spec = rules.spec_for(names, x.shape)
-    from repro.core.jax_compat import manual_axis_names
     manual = manual_axis_names()
     if manual:
         parts = tuple(None if (p in manual or (isinstance(p, tuple) and

@@ -25,6 +25,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.core.sharding import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class VirtualDevice:
@@ -55,7 +57,7 @@ class Cluster:
                 mesh_shape, axis_names = (n,), ("data",)
             axis_names = axis_names or tuple(
                 f"ax{i}" for i in range(len(mesh_shape)))
-            mesh = jax.make_mesh(tuple(mesh_shape), tuple(axis_names))
+            mesh = make_mesh(mesh_shape, axis_names)
         self.mesh = mesh
         self.layout = layout or {}
         # per-device-group Hardware tables (cost_model.ClusterSpec) — None

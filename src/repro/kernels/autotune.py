@@ -69,14 +69,18 @@ class KernelTiles:
 DEFAULT_TILES = KernelTiles()
 
 
-def fit_block(n: int, target: int) -> int:
-    """Largest divisor of ``n`` that is ≤ ``target`` (≥ 1 always exists)."""
+def fit_block(n: int, target: int, align: int = 1) -> int:
+    """Largest divisor of ``n`` that is ≤ ``target`` and a multiple of
+    ``align`` (≥ 1 always exists for ``align=1``; a TPU lane dim passes
+    ``align=128``)."""
     if n <= 0:
         raise ValueError(f"length must be positive, got {n}")
-    t = min(target, n)
-    while n % t:
-        t -= 1
-    return t
+    t = min(target, n) // align * align
+    while t >= align:
+        if n % t == 0:
+            return t
+        t -= align
+    raise ValueError(f"no multiple of {align} ≤ {target} divides {n}")
 
 
 def _pow2_floor(x: float) -> int:

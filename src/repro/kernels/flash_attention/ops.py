@@ -53,12 +53,9 @@ def _flash_bwd(causal, block_q, block_k, interpret, bwd_remat, res, do):
                               block_k=block_k, interpret=interpret)
     else:
         q, k, v, lse, out = res
-    B, Sq, H, D = q.shape
-    K = k.shape[2]
-    G = H // K
     # δ_i = Σ_d do_i·o_i — cheap elementwise reduce, laid out like lse
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(B, Sq, K, G)
+    delta = jnp.swapaxes(jnp.sum(do.astype(jnp.float32)
+                                 * out.astype(jnp.float32), axis=-1), 1, 2)
     dq, dk, dv = flash_attention_bwd(q, k, v, do, lse, delta, causal=causal,
                                      block_q=block_q, block_k=block_k,
                                      interpret=interpret)

@@ -6,15 +6,20 @@ MXU — and (ii) an inter-chunk state recurrence with O(state) carry.  GPU
 implementations split this into 4-5 separate kernels + a host-level scan;
 on TPU we fuse everything into ONE grid walk:
 
+- Head-major layout: x ``(B, H, S, P)``, B/C ``(B, G, S, N)`` (a head reads
+  its group's slab through the index_map — B/C are never repeated per
+  head in HBM) and dt ``(B, H, 1, S)`` as a lane-dense row, so every block's
+  last two dims are (rows, full width) as Mosaic requires.  A is read from
+  SMEM by head index.
 - Grid ``(B, H, L)`` with L (chunk index) as the *minor* sequential axis:
   TPU grid steps execute in order, so the running state h ∈ (P, N) lives in
   a VMEM scratch buffer across chunk steps — the inter-chunk recurrence
   costs zero HBM traffic (the GPU version round-trips states through HBM).
 - Per program: load the chunk's (Q, P) x-tile and (Q, N) B/C tiles, build
-  the (Q, Q) decay mask from the dt cumsum, do the three MXU matmuls
-  (CBᵀ∘L)·x, state read C·h, and state update Bᵀ·(decay∘x).
-- Chunk Q defaults to 128: the (Q, Q) mask matmul and (Q, N)×(N, P)
-  contractions are all 128-aligned for the MXU.
+  the (Q, Q) decay mask from the dt prefix sums, do the three MXU matmuls
+  (CBᵀ∘L)·x, state read C·h, and state update Bᵀ·(decay∘x).  The prefix
+  sums and the row↔column moves are masked (Q, Q) reductions on the VPU,
+  not cumsum/transposes, which Mosaic does not lower for these shapes.
 
 Validated in interpret mode against the sequential-scan oracle (ref.py).
 """
@@ -28,36 +33,41 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
-                *, chunk: int, headdim: int, d_state: int):
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
+                *, chunk: int):
     """Program (b, h, l): one chunk of one head of one batch row.
 
-    x_ref: (Q, P)  dt_ref: (Q,)  a_ref: (1,)  b_ref/c_ref: (Q, N)
+    a_ref: (H,) SMEM   x_ref: (Q, P)   dt_ref: (1, Q)   b_ref/c_ref: (Q, N)
     y_ref: (Q, P)  hout_ref: (P, N)  h_scr: (P, N) VMEM carry.
     """
     li = pl.program_id(2)
-    nl = pl.num_programs(2)
-    Q, P, N = chunk, headdim, d_state
+    Q = chunk
 
     @pl.when(li == 0)
     def _init():
-        h_scr[...] = jnp.zeros((P, N), jnp.float32)
+        h_scr[...] = jnp.zeros(h_scr.shape, jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)              # (Q, P)
-    dt = dt_ref[...].astype(jnp.float32)            # (Q,)
-    A = a_ref[0]                                    # scalar (negative)
+    dt_row = dt_ref[...].astype(jnp.float32)        # (1, Q)
     Bm = b_ref[...].astype(jnp.float32)             # (Q, N)
     Cm = c_ref[...].astype(jnp.float32)             # (Q, N)
+    dA_row = dt_row * a_ref[pl.program_id(1)]       # (1, Q) ≤ 0
 
-    dA = dt * A                                     # (Q,) ≤ 0
-    cum = jnp.cumsum(dA)                            # (Q,)
-    # intra-chunk decay mask  L[i, j] = exp(cum_i − cum_j) · (i ≥ j)
-    seg = cum[:, None] - cum[None, :]
     iota = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jota = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    Lmask = jnp.where(iota >= jota, jnp.exp(seg), 0.0)
+    lower, diag = iota >= jota, iota == jota
 
-    xd = x * dt[:, None]                            # dt-weighted input
+    def to_col(row):                                # (1, Q) → (Q, 1)
+        return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+
+    # prefix sums cum_i = Σ_{j≤i} dA_j, as a column and as a row
+    cum = jnp.sum(jnp.where(lower, dA_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(diag, cum, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(dA_row, axis=1, keepdims=True)  # (1, 1)
+    # intra-chunk decay mask  L[i, j] = exp(cum_i − cum_j) · (i ≥ j)
+    Lmask = jnp.where(lower, jnp.exp(cum - cum_row), 0.0)
+
+    xd = x * to_col(dt_row)                         # dt-weighted input
     # --- dual quadratic form on the MXU ---
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q, Q)
@@ -68,18 +78,16 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
     h = h_scr[...]                                  # (P, N)
     y_off = jax.lax.dot_general(Cm, h, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)   # (Q, P)
-    y_ref[...] = (y_diag + y_off * jnp.exp(cum)[:, None]).astype(y_ref.dtype)
+    y_ref[...] = (y_diag + y_off * jnp.exp(cum)).astype(y_ref.dtype)
 
     # --- state update: h' = exp(sum dA) · h + Σ_q exp(cum_Q − cum_q) Bq ⊗ xdq
-    total = cum[Q - 1]
-    decay_to_end = jnp.exp(total - cum)             # (Q,)
     state_upd = jax.lax.dot_general(
-        xd * decay_to_end[:, None], Bm, (((0,), (0,)), ((), ())),
+        xd * jnp.exp(total - cum), Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)         # (P, N)
     h_new = h * jnp.exp(total) + state_upd
     h_scr[...] = h_new
 
-    @pl.when(li == nl - 1)
+    @pl.when(li == pl.num_programs(2) - 1)
     def _emit():
         hout_ref[...] = h_new
 
@@ -98,30 +106,38 @@ def ssd_scan_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
         raise ValueError(f"S={S} must divide chunk={chunk}")
     L = S // chunk
     rep = H // G
-    if rep > 1:   # broadcast groups to heads for uniform BlockSpecs
-        Bm = jnp.repeat(Bm, rep, axis=2)
-        Cm = jnp.repeat(Cm, rep, axis=2)
 
-    grid = (Bsz, H, L)
+    xh = jnp.swapaxes(x, 1, 2)                          # (B, H, S, P)
+    dth = jnp.swapaxes(dt, 1, 2)[:, :, None, :]         # (B, H, 1, S)
+    Bh, Ch = jnp.swapaxes(Bm, 1, 2), jnp.swapaxes(Cm, 1, 2)   # (B, G, S, N)
+
+    def own(b, h, l):
+        return (b, h, l, 0)
+
+    def group(b, h, l):
+        return (b, h // rep, l, 0)
+
     y, hT = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=chunk, headdim=P, d_state=N),
-        grid=grid,
+        functools.partial(_ssd_kernel, chunk=chunk),
+        grid=(Bsz, H, L),
         in_specs=[
-            pl.BlockSpec((None, chunk, None, P), lambda b, h, l: (b, l, h, 0)),
-            pl.BlockSpec((None, chunk, None), lambda b, h, l: (b, l, h)),
-            pl.BlockSpec((1,), lambda b, h, l: (h,)),
-            pl.BlockSpec((None, chunk, None, N), lambda b, h, l: (b, l, h, 0)),
-            pl.BlockSpec((None, chunk, None, N), lambda b, h, l: (b, l, h, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, chunk, P), own),
+            pl.BlockSpec((None, None, 1, chunk), lambda b, h, l: (b, h, 0, l)),
+            pl.BlockSpec((None, None, chunk, N), group),
+            pl.BlockSpec((None, None, chunk, N), group),
         ],
         out_specs=(
-            pl.BlockSpec((None, chunk, None, P), lambda b, h, l: (b, l, h, 0)),
+            pl.BlockSpec((None, None, chunk, P), own),
             pl.BlockSpec((None, None, P, N), lambda b, h, l: (b, h, 0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((Bsz, S, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((Bsz, H, P, N), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
-    return y, hT
+    )(A.astype(jnp.float32), xh, dth, Bh, Ch)
+    return jnp.swapaxes(y, 1, 2), hT

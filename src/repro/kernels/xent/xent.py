@@ -9,9 +9,11 @@ online max / sum-exp / label-logit reduction per token row:
   (fastest-moving) axis so the (block_t, E) hidden tile stays resident in
   VMEM across the whole vocab sweep while weight tiles (E, block_v) stream
   through — one HBM pass over the head weights per token block.
-- The partial state (m, l, correct) is carried in the *output* refs across
+- The partial state (m, l, correct) is carried in VMEM scratch across
   grid steps (TPU grids execute sequentially over the minor axis, the
   standard Pallas accumulation idiom) and finalised on the last vocab tile.
+- Operands reach the MXU in their own dtype (bf16 in training) with f32
+  accumulation; per-token labels and results are (T, 1) columns.
 - The (block_t, block_v) logits tile is MXU-shaped ((128, 512) by default)
   and exists only in VMEM: HBM traffic drops from O(T·V) to O(T·E + E·V),
   which is what makes the 256k-vocab gemma/seamless heads trainable.
@@ -29,48 +31,48 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _xent_kernel(h_ref, w_ref, lab_ref, nll_ref, lse_ref, m_ref, l_ref,
-                 c_ref, *, block_t: int, block_v: int, vocab: int):
-    """Program (ti, vi): logits tile = h_tile @ w_tile, online reduce."""
-    vi = pl.program_id(1)
-    nv = pl.num_programs(1)
+def _xent_kernel(h_ref, w_ref, lab_ref, nll_ref, lse_ref, m_sc, l_sc, c_sc,
+                 *, block_t: int, block_v: int, vocab: int):
+    """Program (ti, vi): logits tile = h_tile @ w_tile, online reduce.
 
-    h = h_ref[...].astype(jnp.float32)                       # (bt, E)
-    w = w_ref[...].astype(jnp.float32)                       # (E, bv)
-    logits = jax.lax.dot_general(h, w, (((1,), (0,)), ((), ())),
+    h_ref: (bt, E)  w_ref: (E, bv)  lab_ref/nll_ref/lse_ref: (bt, 1)
+    scratch m/l/c: (bt, 1) f32 — running max, sum-exp, label logit.
+    """
+    vi = pl.program_id(1)
+
+    @pl.when(vi == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        c_sc[...] = jnp.zeros(c_sc.shape, jnp.float32)
+
+    logits = jax.lax.dot_general(h_ref[...], w_ref[...],
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     col = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, (block_t, block_v), 1)
     logits = jnp.where(col < vocab, logits, NEG_INF)         # padded cols
+    hit = col == lab_ref[...]                                # (bt, bv)
 
-    lab = lab_ref[...]                                       # (bt,)
-    hit = (col == lab[:, None])
-    corr_tile = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-
-    @pl.when(vi == 0)
-    def _init():
-        m_ref[...] = jnp.full((block_t,), NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros((block_t,), jnp.float32)
-        c_ref[...] = jnp.zeros((block_t,), jnp.float32)
-
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+    m_prev = m_sc[...]
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.exp(logits - m_new[:, None]).sum(axis=-1)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    c_ref[...] = c_ref[...] + corr_tile
+    m_sc[...] = m_new
+    l_sc[...] = l_sc[...] * corr + jnp.exp(logits - m_new).sum(
+        axis=-1, keepdims=True)
+    c_sc[...] = c_sc[...] + jnp.sum(jnp.where(hit, logits, 0.0), axis=-1,
+                                    keepdims=True)
 
-    @pl.when(vi == nv - 1)
+    @pl.when(vi == pl.num_programs(1) - 1)
     def _finalize():
-        lse = jnp.log(jnp.maximum(l_ref[...], 1e-30)) + m_ref[...]
+        lse = jnp.log(jnp.maximum(l_sc[...], 1e-30)) + m_sc[...]
         lse_ref[...] = lse
-        nll_ref[...] = lse - c_ref[...]
+        nll_ref[...] = lse - c_sc[...]
 
 
 def xent_fwd(hidden: jax.Array, head_w: jax.Array, labels: jax.Array, *,
@@ -87,15 +89,11 @@ def xent_fwd(hidden: jax.Array, head_w: jax.Array, labels: jax.Array, *,
                          f"({block_t}, {block_v})")
     nt, nv = T // block_t, V // block_v
 
-    out_shapes = (
-        jax.ShapeDtypeStruct((T,), jnp.float32),   # nll
-        jax.ShapeDtypeStruct((T,), jnp.float32),   # lse
-        jax.ShapeDtypeStruct((T,), jnp.float32),   # m (scratch-as-output)
-        jax.ShapeDtypeStruct((T,), jnp.float32),   # l
-        jax.ShapeDtypeStruct((T,), jnp.float32),   # correct
-    )
-    row = pl.BlockSpec((block_t,), lambda t, v: (t,))
-    nll, lse, _, _, _ = pl.pallas_call(
+    # per-token values travel as (T, 1) columns: a lane-padded 2-D block
+    # is a layout Mosaic accepts, a rank-1 (block_t,) block is not
+    row = pl.BlockSpec((block_t, 1), lambda t, v: (t, 0))
+    col = jax.ShapeDtypeStruct((T, 1), jnp.float32)
+    nll, lse = pl.pallas_call(
         functools.partial(_xent_kernel, block_t=block_t, block_v=block_v,
                           vocab=vocab),
         grid=(nt, nv),
@@ -104,8 +102,11 @@ def xent_fwd(hidden: jax.Array, head_w: jax.Array, labels: jax.Array, *,
             pl.BlockSpec((E, block_v), lambda t, v: (0, v)),
             row,
         ],
-        out_specs=(row, row, row, row, row),
-        out_shape=out_shapes,
+        out_specs=(row, row),
+        out_shape=(col, col),
+        scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(hidden, head_w, labels)
-    return nll, lse
+    )(hidden, head_w, labels.astype(jnp.int32)[:, None])
+    return nll[:, 0], lse[:, 0]

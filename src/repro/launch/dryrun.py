@@ -1,5 +1,8 @@
 import os
+# a CPU tool: 512 virtual host devices stand in for the pod, and no chip
+# on the machine is touched
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -39,6 +42,7 @@ from repro.configs import shapes as sh
 from repro.core.cost_model import TPU_V5E, StrategySpec
 from repro.core.ir import jaxpr_flops
 from repro.core.planner import compile_plan
+from repro.core.sharding import make_mesh
 from repro.launch.hlo_analysis import collective_bytes, hbm_traffic_bytes
 from repro.launch.mesh import make_production_mesh
 from repro.models.lm import build, param_count
@@ -142,7 +146,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
     t_start = time.time()
     if mesh_shape is not None:               # perf-iteration mesh override
         names = ("pod", "data", "model")[-len(mesh_shape):]
-        mesh = jax.make_mesh(mesh_shape, names)
+        mesh = make_mesh(mesh_shape, names)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_dev = mesh.devices.size
@@ -224,8 +228,6 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # jax<=0.4 returns [dict] per device
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo, n_dev)
     hbm_dev = hbm_traffic_bytes(hlo)
@@ -292,7 +294,8 @@ def _run_all(args) -> int:
         if args.multi_pod:
             cmd.append("--multi-pod")
         t0 = time.time()
-        p = subprocess.run(cmd, capture_output=True, text=True)
+        p = subprocess.run(cmd, capture_output=True, text=True,
+                           env=dict(os.environ, JAX_PLATFORMS="cpu"))
         dt = time.time() - t0
         if p.returncode:
             failures += 1
@@ -352,8 +355,8 @@ def main() -> None:
         if args.mesh_shape else None
     strategy = None
     if args.no_vocab_split:
-        base = (jax.make_mesh(mesh_shape,
-                              ("pod", "data", "model")[-len(mesh_shape):])
+        base = (make_mesh(mesh_shape,
+                          ("pod", "data", "model")[-len(mesh_shape):])
                 if mesh_shape else make_production_mesh(
                     multi_pod=args.multi_pod))
         strategy = dataclasses.replace(

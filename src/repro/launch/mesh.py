@@ -13,15 +13,13 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple, axes: tuple):
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: int | None = None, *,
@@ -31,5 +29,5 @@ def make_host_mesh(model: int = 1, data: int | None = None, *,
     if data is None:
         data = n // (model * stage)
     if stage > 1:
-        return jax.make_mesh((stage, data, model), ("stage", "data", "model"))
-    return jax.make_mesh((data, model), axes_order)
+        return make_mesh((stage, data, model), ("stage", "data", "model"))
+    return make_mesh((data, model), axes_order)

@@ -22,6 +22,7 @@ Usage (CPU sanity)::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -29,6 +30,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.core.planner import compile_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import parse_mesh
 from repro.serving.metrics import RequestTiming, ServeMetrics
 from repro.serving.server import Request, Server
@@ -121,15 +123,21 @@ def main(argv=None) -> dict:
                          "accounting instead of the drain-the-queue loop")
     ap.add_argument("--rate", type=float, default=4.0,
                     help="--traffic mean arrival rate (req/s)")
+    ap.add_argument("--attn", choices=("ref", "pallas"), default=None,
+                    help="attention impl: pallas = flash prefill + paged "
+                         "decode kernels (interpret-mode on CPU); default: "
+                         "config's choice")
     ap.add_argument("--mesh", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.models.lm import build
     cfg = get_config(args.arch, smoke=args.smoke)
+    if args.attn:
+        cfg = dataclasses.replace(cfg, attn_impl=args.attn)
     model = build(cfg)
-    mesh = parse_mesh(args.mesh) if args.mesh else jax.make_mesh(
-        (len(jax.devices()),), ("data",))
+    mesh = parse_mesh(args.mesh or str(len(jax.devices())))
     plan = compile_plan(model, mesh)
     with mesh:
         params = plan.init_params(jax.random.key(args.seed))
@@ -198,7 +206,8 @@ def main(argv=None) -> dict:
           f"{server.steps} decode steps, "
           f"{server.prefill_cache_size} prefill buckets)")
     return {"steps": server.steps, "seconds": dt,
-            "completed": len(done), "tokens": total_toks}
+            "completed": len(done), "tokens": total_toks,
+            "request_tokens": {r.rid: len(r.out_tokens) for r in done}}
 
 
 if __name__ == "__main__":

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -50,9 +51,12 @@ import jax.numpy as jnp
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs import ARCH_NAMES, get_config
 from repro.core.auto import auto_parallel
-from repro.core.cost_model import StrategySpec, TPU_V5E, step_cost_features
+from repro.core.cost_model import (HARDWARE_BY_NAME, StrategySpec,
+                                   device_hardware, step_cost_features)
 from repro.core.planner import compile_plan, mesh_for_strategy
+from repro.core.sharding import make_mesh
 from repro.data.pipeline import DataCfg, MultimodalPipeline, TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.optimizer import Schedule, adamw, adafactor
 from repro.runtime.controller import (CalibrationConfig, ClusterController,
                                       ElasticConfig)
@@ -68,13 +72,11 @@ from repro.runtime.straggler import StragglerMonitor
 TrainController = ClusterController
 
 
-def parse_mesh(spec: str, *, stage: int = 1):
+def parse_mesh(spec: str):
+    """``"4"`` → data 4; ``"4x2"`` → data 4 × model 2; ``"2x4x2"`` adds pod."""
     dims = tuple(int(x) for x in spec.split("x"))
-    if len(dims) == 1:
-        return jax.make_mesh(dims, ("data",))
-    if len(dims) == 2:
-        return jax.make_mesh(dims, ("data", "model"))
-    return jax.make_mesh(dims, ("pod", "data", "model"))
+    names = {1: ("data",), 2: ("data", "model"), 3: ("pod", "data", "model")}
+    return make_mesh(dims, names[len(dims)])
 
 
 def _parse_injections(slow: list, crash: list, drift: list = (),
@@ -137,7 +139,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--auto", action="store_true",
                     help="pick the strategy with the Whale cost model")
     ap.add_argument("--compress-pod", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory to save to and resume from; "
+                         "default: a fresh temporary directory (no resume)")
     ap.add_argument("--save-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
@@ -150,10 +154,10 @@ def main(argv=None) -> dict:
                          "(interpret-mode off-TPU); default: config's choice")
     ap.add_argument("--xent", choices=("ref", "pallas"), default=None,
                     help="loss head impl: pallas = fused xent kernel")
-    ap.add_argument("--hw", choices=("tpu_v5e", "v100", "p100", "t4"),
-                    default="tpu_v5e",
-                    help="Hardware table the kernel-tile autotuner targets "
-                         "(repro.kernels.autotune)")
+    ap.add_argument("--hw", choices=sorted(HARDWARE_BY_NAME), default=None,
+                    help="Hardware table the kernel-tile autotuner and the "
+                         "--profile report target (repro.kernels.autotune); "
+                         "default: the table of the device in use")
     # ---- self-healing elastic runtime (DESIGN.md §7) ----
     ap.add_argument("--hosts", type=int, default=0,
                     help="simulate N hosts over the visible devices and run "
@@ -204,9 +208,11 @@ def main(argv=None) -> dict:
                     help="fault injection: HOST ramps linearly to FACTOR× "
                          "slower between START and END (repeatable)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()
+    hw = HARDWARE_BY_NAME[args.hw] if args.hw else device_hardware()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.overrides:
@@ -223,10 +229,7 @@ def main(argv=None) -> dict:
     if "pallas" in (cfg.attn_impl, cfg.xent_impl, cfg.ssd_impl):
         # size the kernel tiles for the target part (per-Hardware autotune);
         # mixed clusters get per-group tiles on the plan via compile_plan
-        from repro.core import cost_model as _cm
         from repro.kernels.autotune import autotune
-        hw = {"tpu_v5e": _cm.TPU_V5E, "v100": _cm.V100_PAPER,
-              "p100": _cm.P100_16G, "t4": _cm.T4_16G}[args.hw]
         tiles = autotune(
             hw, head_dim=cfg.hd if cfg.n_heads else cfg.ssd_headdim,
             group=cfg.n_heads // max(cfg.n_kv_heads, 1) or 1,
@@ -257,7 +260,8 @@ def main(argv=None) -> dict:
             src_len=src_seq if cfg.family == "encdec" else 0)
     else:
         data = TokenPipeline(dcfg)
-    ckpt = CheckpointManager(args.ckpt_dir, keep=2)
+    ckpt = CheckpointManager(
+        args.ckpt_dir or tempfile.mkdtemp(prefix="repro_ckpt_"), keep=2)
 
     # ---- self-healing controller path (simulated multi-host) ----
     if args.hosts > 1:
@@ -274,7 +278,7 @@ def main(argv=None) -> dict:
                 raise SystemExit(f"--hosts {args.hosts} must divide the "
                                  f"device count ({n})")
             dph = n // args.hosts
-        topology = HostTopology.uniform(args.hosts, dph, TPU_V5E)
+        topology = HostTopology.uniform(args.hosts, dph, hw)
         scenarios = _parse_injections(args.inject_slow, args.inject_crash,
                                       args.inject_drift,
                                       args.inject_preempt, args.inject_join)
@@ -326,8 +330,7 @@ def main(argv=None) -> dict:
         # decoder boundaries when it enumerates pipeline splits
         graph = model.graph(args.batch, args.seq, src_seq=src_seq)
         search_kw = {"max_pp": 1} if cfg.family == "vlm" else {}
-        strat = auto_parallel(graph, len(jax.devices()), TPU_V5E,
-                              **search_kw)
+        strat = auto_parallel(graph, len(jax.devices()), hw, **search_kw)
         print(f"[auto] chose: {strat.describe()}")
         mesh = mesh_for_strategy(strat)
     elif args.pp > 1:
@@ -346,8 +349,7 @@ def main(argv=None) -> dict:
                              schedule=args.schedule or "gpipe")
         mesh = mesh_for_strategy(strat)
     else:
-        mesh = parse_mesh(args.mesh) if args.mesh else jax.make_mesh(
-            (len(jax.devices()),), ("data",))
+        mesh = parse_mesh(args.mesh or str(len(jax.devices())))
         strat = None
     plan = compile_plan(model, mesh, strategy=strat)
     pipelined = plan.strategy.pp > 1 and "stage" in mesh.shape
@@ -426,14 +428,11 @@ def main(argv=None) -> dict:
         # whole-step observations against the executed strategy's feature
         # vector on the --hw table; the exit report shows how far the
         # hand-written rates are from this machine's measured ones
-        from repro.core import cost_model as _cm
-        prof_hw = {"tpu_v5e": _cm.TPU_V5E, "v100": _cm.V100_PAPER,
-                   "p100": _cm.P100_16G, "t4": _cm.T4_16G}[args.hw]
         prof_meta = model.graph(args.batch, args.seq,
                                 src_seq=src_seq).workload_meta()
-        prof_feats = step_cost_features(prof_meta, plan.strategy, prof_hw)
+        prof_feats = step_cost_features(prof_meta, plan.strategy, hw)
         profiler = Profiler()
-    losses = []
+    losses, step_seconds = [], []
     state0 = {"params": params, "opt": opt_state}
     if args.compress_pod and "pod" in mesh.shape:
         from repro.optim import grad_compress
@@ -467,8 +466,9 @@ def main(argv=None) -> dict:
         return new
 
     def on_step(i, st, dt):
+        step_seconds.append(dt)
         if profiler is not None and i > start_step:
-            profiler.record_step(prof_hw.name, dt, prof_feats, step=i)
+            profiler.record_step(hw.name, dt, prof_feats, step=i)
         if monitor.observe(dt):       # one-shot: True on the flag transition
             print(f"[straggler] flagged at step {i} "
                   f"(dt={dt:.3f}s vs mean {monitor.mean:.3f}s)")
@@ -483,12 +483,13 @@ def main(argv=None) -> dict:
 
     if profiler is not None:
         from repro.core.cost_model import ClusterSpec
-        print(profiler.report(ClusterSpec.homogeneous(prof_hw,
+        print(profiler.report(ClusterSpec.homogeneous(hw,
                                                       len(jax.devices()))))
     loss_str = (f", loss {losses[0]:.4f} → {losses[-1]:.4f}" if losses
                 else " (resumed already complete)")
     print(f"[done] step {final_step}{loss_str}")
-    return {"final_step": final_step, "losses": losses}
+    return {"final_step": final_step, "losses": losses,
+            "step_seconds": step_seconds, "state": state}
 
 
 if __name__ == "__main__":

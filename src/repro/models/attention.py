@@ -28,7 +28,6 @@ instead of an all-gather of the cache.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -240,12 +239,12 @@ def attention(params: dict, x: jax.Array, positions: jax.Array, cfg: AttnCfg,
     v = constrain(v, kv_names)
 
     if impl == "pallas":
+        from repro.kernels import interpret_mode
         from repro.kernels.flash_attention import flash
         Bq, Sq, Kq, Gq, Dq = qg.shape
         out = flash(
             qg.reshape(Bq, Sq, Kq * Gq, Dq), k, v, cfg.causal,
-            min(block_q, Sq), min(block_k, S),
-            jax.default_backend() != "tpu", bwd_remat,
+            min(block_q, Sq), min(block_k, S), interpret_mode(), bwd_remat,
         ).reshape(Bq, Sq, Kq, Gq, Dq).astype(jnp.float32)
     else:
         out = _blocked_gqa(qg, k, v, causal=cfg.causal,
@@ -384,7 +383,7 @@ def paged_scatter(pool: jax.Array, block_table: jax.Array, pos: jax.Array,
     """Write ``new`` (B, K, D) into the page pool cell each slot's ``pos``
     maps to through its block table.
 
-    pool: (P, page_size, K, D); block_table: (B, max_pages) int32 (0 = the
+    pool: (P, K, page_size, D); block_table: (B, max_pages) int32 (0 = the
     reserved trash page); pos: (B,).  One-hot outer-product ADD, like the
     dense cache's scatter, so the write is jit-shaped for every slot — but
     writes that resolve to the trash page (inactive slots, unallocated
@@ -393,13 +392,13 @@ def paged_scatter(pool: jax.Array, block_table: jax.Array, pos: jax.Array,
     allocated, each cell written once), so ``0 + new`` stores ``new``
     bit-exactly.
     """
-    P, ps = pool.shape[0], pool.shape[1]
+    P, ps = pool.shape[0], pool.shape[2]
     page_idx = pos // ps
     phys = jnp.take_along_axis(block_table, page_idx[:, None], axis=1)[:, 0]
     live = (phys != 0).astype(jnp.float32)
     oh_page = jax.nn.one_hot(phys, P, dtype=jnp.float32) * live[:, None]
     oh_row = jax.nn.one_hot(pos % ps, ps, dtype=jnp.float32)
-    delta = jnp.einsum("bp,br,bkd->prkd", oh_page.astype(pool.dtype),
+    delta = jnp.einsum("bp,br,bkd->pkrd", oh_page.astype(pool.dtype),
                        oh_row.astype(pool.dtype), new.astype(pool.dtype))
     return pool + delta
 
@@ -407,10 +406,10 @@ def paged_scatter(pool: jax.Array, block_table: jax.Array, pos: jax.Array,
 def paged_decode_attention(params: dict, x: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_table: jax.Array,
                            pos: jax.Array, cfg: AttnCfg, *,
-                           impl: str = "ref", page_interpret: bool | None = None):
+                           impl: str = "ref"):
     """Decode step against a paged KV cache.
 
-    x: (B, E); k_pool/v_pool: (P, page_size, K, D) physical page pools
+    x: (B, E); k_pool/v_pool: (P, K, page_size, D) physical page pools
     shared by all slots; block_table: (B, max_pages) int32 slot→page map
     (entry 0 = the trash page); pos: (B,).  Returns (y, k_pool', v_pool').
 
@@ -426,12 +425,15 @@ def paged_decode_attention(params: dict, x: jax.Array, k_pool: jax.Array,
     """
     B, E = x.shape
     K, G, D = cfg.n_kv_heads, cfg.group, cfg.head_dim
-    P, ps = k_pool.shape[0], k_pool.shape[1]
+    ps = k_pool.shape[2]
     max_pages = block_table.shape[1]
 
+    def gather(pool):          # (B, max_pages, K, ps, D) → (B, S, K, D)
+        return jnp.swapaxes(pool[block_table], 2, 3).reshape(
+            B, max_pages * ps, K, D)
+
     if impl == "ref":
-        kd = k_pool[block_table].reshape(B, max_pages * ps, K, D)
-        vd = v_pool[block_table].reshape(B, max_pages * ps, K, D)
+        kd, vd = gather(k_pool), gather(v_pool)
         y, k_upd, v_upd = decode_attention(params, x, kd, vd, pos, cfg)
         # the pos cell was zero pre-add, so the one-hot row-pick recovers
         # the freshly written post-rope k/v exactly (1·k + Σ 0·finite = k)
@@ -443,11 +445,10 @@ def paged_decode_attention(params: dict, x: jax.Array, k_pool: jax.Array,
     k_pool = paged_scatter(k_pool, block_table, pos, k_new)
     v_pool = paged_scatter(v_pool, block_table, pos, v_new)
     if impl != "ref":
+        from repro.kernels import interpret_mode
         from repro.kernels.flash_attention import paged_decode
-        if page_interpret is None:
-            page_interpret = jax.default_backend() != "tpu"
         out = paged_decode(q, k_pool, v_pool, block_table, pos,
-                           interpret=page_interpret)
+                           interpret=interpret_mode())
         out = out.astype(x.dtype)
         y = jnp.einsum("bhd,hde->be", out, params["wo"].astype(x.dtype))
     return y, k_pool, v_pool

@@ -248,17 +248,22 @@ def fused_xent(hidden: jax.Array, head_w: jax.Array, labels: jax.Array,
     the z-loss term differentiates through the same recompute-over-vocab
     backward (``kernels.xent.ops.xent_with_lse``).
     """
+    from repro.kernels import interpret_mode
     from repro.kernels.autotune import fit_block
     from repro.kernels.xent.ops import xent_with_lse
     B, T, E = hidden.shape
     Vp = head_w.shape[1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    h2 = hidden.reshape(B * T, E)
-    l2 = labels.reshape(B * T)
-    m2 = mask.reshape(B * T).astype(jnp.float32)
-    bt = fit_block(B * T, block_t)
-    bv = fit_block(Vp, block_v)
+        interpret = interpret_mode()
+    # rows padded (mask 0) to whole token tiles of a sublane multiple;
+    # the vocab tile is a lane-aligned divisor of the padded vocab
+    n = B * T
+    bt = min(block_t, -(-n // 8) * 8)
+    pad = -(-n // bt) * bt - n
+    h2 = jnp.pad(hidden.reshape(n, E), ((0, pad), (0, 0)))
+    l2 = jnp.pad(labels.reshape(n), (0, pad))
+    m2 = jnp.pad(mask.reshape(n).astype(jnp.float32), (0, pad))
+    bv = fit_block(Vp, block_v, align=128)
     nll, lse = xent_with_lse(h2, head_w, l2, vocab, bt, bv, interpret)
     s_nll = jnp.sum(nll * m2)
     s_zl = jnp.sum(jnp.square(lse) * m2)

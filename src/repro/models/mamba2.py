@@ -179,9 +179,10 @@ def ssd_block(params: dict, x: jax.Array, cfg: SSDCfg,
 
     A = -jnp.exp(params["A_log"])
     if impl == "pallas":
+        from repro.kernels import interpret_mode
         from repro.kernels.ssd import ops as ssd_ops
         y, _ = ssd_ops.ssd(xi, dt, A, Bm, Cm, chunk=min(cfg.chunk, S),
-                           interpret=jax.default_backend() != "tpu")
+                           interpret=interpret_mode())
     else:
         y, _ = ssd_scan(xi, dt, A, Bm, Cm, chunk=cfg.chunk)
     y = y.astype(x.dtype)

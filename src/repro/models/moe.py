@@ -209,8 +209,6 @@ def moe_block_ep(params: dict, x: jax.Array, cfg: MoECfg, mesh, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.jax_compat import shard_map
-
     ep = mesh.shape[axis]
     B = x.shape[0]
     E = cfg.n_experts
@@ -266,7 +264,7 @@ def moe_block_ep(params: dict, x: jax.Array, cfg: MoECfg, mesh, *,
     if "shared" in params:
         pspec["shared"] = jax.tree.map(lambda _: P(), params["shared"])
     aux_spec = {"lb_loss": P(), "z_loss": P(), "expert_load": P()}
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(pspec, P(axis)),
-                   out_specs=(P(axis), aux_spec))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(pspec, P(axis)),
+                       out_specs=(P(axis), aux_spec), check_vma=False)
     return fn(params, x)

@@ -335,7 +335,8 @@ def _check_paged(stack: StackCfg):
 
 def init_paged_stack_state(stack: StackCfg, n_pages: int, page_size: int,
                            dtype) -> dict:
-    """Per-pattern-position page pools ``(n_rep, n_pages, page_size, K, D)``.
+    """Per-pattern-position page pools ``(n_rep, n_pages, K, page_size, D)``
+    (head-major, so a page of one kv head is a ``(page_size, D)`` tile).
 
     Pools are *slot-free*: every decode slot shares them through its block
     table row, which is what lets short sequences stop reserving
@@ -345,7 +346,7 @@ def init_paged_stack_state(stack: StackCfg, n_pages: int, page_size: int,
     pools = {}
     for i, bcfg in enumerate(stack.pattern):
         a = bcfg.attn
-        shape = (n_pages, page_size, a.n_kv_heads, a.head_dim)
+        shape = (n_pages, a.n_kv_heads, page_size, a.head_dim)
         s = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         pools[f"p{i}"] = jax.tree.map(
             lambda t: jnp.broadcast_to(t, (stack.n_rep,) + t.shape), s)
@@ -356,7 +357,7 @@ def axes_paged_stack_state(stack: StackCfg) -> dict:
     """Pools shard like the dense cache minus the batch dim: pages and
     rows replicated, kv heads on the model axis."""
     _check_paged(stack)
-    n = ("layers", None, None, "kv_heads", None)
+    n = ("layers", None, "kv_heads", None, None)
     return {f"p{i}": {"k": n, "v": n} for i in range(len(stack.pattern))}
 
 

@@ -56,8 +56,7 @@ def compressed_psum(x: jax.Array, axis: str, err: jax.Array | None = None,
     total = jax.lax.psum(q2.astype(jnp.int32), axis)
     out = total.astype(jnp.float32) * smax
     if mean:
-        from repro.core.jax_compat import axis_size
-        out = out / axis_size(axis)
+        out = out / jax.lax.axis_size(axis)
     return out.astype(x.dtype), new_err.astype(jnp.float32)
 
 

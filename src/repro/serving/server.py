@@ -27,7 +27,6 @@ Decode hot path does exactly **one** host sync per step: a single
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -214,6 +213,7 @@ class Server:
                 else:
                     a = a[:, :rows]
                 a = a.reshape(a.shape[0], len(pages), ps, *a.shape[2:])
+                a = jnp.swapaxes(a, 2, 3)            # pools are head-major
                 pool = self.pools[name][key]
                 self.pools[name][key] = pool.at[:, idx].set(
                     a.astype(pool.dtype))

@@ -1,78 +1,5 @@
 """Pytest config — NOTE: no XLA_FLAGS here; smoke tests run single-device.
-Multi-device coverage lives in test_distributed.py via subprocesses.
-
-Also installs a skip-if-missing shim for ``hypothesis``: property tests are
-written against the real library (see requirements-dev.txt), but the bare
-container may not ship it.  Rather than failing the whole module at
-collection (ModuleNotFoundError), the shim below makes every
-``@given``-decorated test an individual skip, so the rest of the suite
-stays green.
-"""
-import sys
-import types
-
-import pytest
-
-
-def _install_hypothesis_stub() -> None:
-    """Register a fake ``hypothesis`` package whose ``@given`` skips the test.
-
-    Only activated when the real library is absent.  The stub mirrors the
-    small API surface the test-suite uses (``given``, ``settings``,
-    ``strategies.*``, ``HealthCheck``); strategy constructors return opaque
-    placeholders since the decorated test body never runs.
-    """
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    mod = types.ModuleType("hypothesis")
-    mod.__doc__ = "skip-if-missing shim installed by tests/conftest.py"
-
-    def given(*_a, **_k):
-        def deco(fn):
-            @pytest.mark.skip(reason="hypothesis not installed "
-                                     "(pip install -r requirements-dev.txt)")
-            def skipped(*args, **kwargs):   # pragma: no cover - never runs
-                pass
-            skipped.__name__ = getattr(fn, "__name__", "hypothesis_test")
-            skipped.__doc__ = getattr(fn, "__doc__", None)
-            return skipped
-        return deco
-
-    def settings(*_a, **_k):
-        def deco(fn):
-            return fn
-        return deco
-
-    class _Strategy:
-        """Opaque placeholder; supports chaining (.map/.filter/.flatmap)."""
-
-        def __call__(self, *a, **k):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-    class _Strategies(types.ModuleType):
-        def __getattr__(self, name):
-            return _Strategy()
-
-    st = _Strategies("hypothesis.strategies")
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = st
-    mod.HealthCheck = types.SimpleNamespace(
-        too_slow=None, data_too_large=None, filter_too_much=None)
-    mod.assume = lambda *_a, **_k: True
-    mod.example = lambda *_a, **_k: (lambda fn: fn)
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st
-
-
-_install_hypothesis_stub()
+Multi-device coverage lives in test_distributed.py via subprocesses."""
 
 
 def pytest_configure(config):

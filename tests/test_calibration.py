@@ -16,10 +16,6 @@ Layers under test:
       and resumes (subprocess, simulated clock).
 """
 import dataclasses
-import os
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -38,18 +34,7 @@ from repro.models.lm import model_graph
 from repro.runtime.faults import DriftHost, FaultInjector
 from repro.runtime.profiler import Profiler, ring_effective_bytes
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def run_py(code: str, devices: int = 4, timeout: int = 540):
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=env, cwd=ROOT)
-    assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
-    return p.stdout
+from subproc import run_py
 
 
 def _meta(batch=256, seq=512, arch="tinyllama-1.1b"):

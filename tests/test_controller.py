@@ -10,10 +10,6 @@ deadline missed → fall back to the last committed checkpoint
 exactly-once) run in subprocesses with virtual CPU devices.
 """
 import itertools
-import os
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -38,7 +34,7 @@ from repro.runtime.fault_tolerance import FaultTolerantLoop
 from repro.runtime.faults import FaultInjector, JoinHost, SpotPreemption
 from repro.runtime.straggler import HostStragglerAggregator
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from subproc import run_py
 
 ALL_STATES = (RUNNING, DRAINING, REBALANCING, RESUMING, DONE, PREEMPTED,
               FAILED)
@@ -51,17 +47,6 @@ def _events(step=3):
             PreemptionWarning(step=step, host=1, deadline_step=step + 2),
             HostLost(step=step, host=1),
             HostJoin(step=step, host=SimHost(7, TPU_V5E, 2)))
-
-
-def run_py(code: str, devices: int = 4, timeout: int = 540):
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=env, cwd=ROOT)
-    assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
-    return p.stdout
 
 
 # ---------------------------------------------------------------------------

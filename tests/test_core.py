@@ -9,9 +9,10 @@ import repro as wh
 from repro.core.auto import enumerate_strategies, search
 from repro.core.cost_model import (StrategySpec, TPU_V5E, V100_PAPER,
                                    WorkloadMeta, all_gather_time,
-                                   all_reduce_time, step_cost)
+                                   all_reduce_time, device_hardware,
+                                   step_cost)
 from repro.core.ir import TaskGraph, TensorMeta, capture_meta, jaxpr_flops
-from repro.core.sharding import hybrid_rules
+from repro.core.sharding import constrain, hybrid_rules, make_mesh, use_rules
 from repro.models.lm import model_graph
 
 
@@ -114,7 +115,37 @@ def test_pipeline_scope_records_stages_and_micro():
 # ---------------------------------------------------------------------------
 
 def _mesh(shape, names):
-    return jax.make_mesh(shape, names)
+    return make_mesh(shape, names)
+
+
+def test_make_mesh_axes_are_auto():
+    """Explicit axes (jax's default) refuse the rules' sharding constraints."""
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
+    with mesh, use_rules(hybrid_rules(mesh)):
+        y = jax.jit(lambda x: constrain(x * 2, ("batch", None)))(
+            jnp.ones((4, 8)))
+    assert float(y.sum()) == 64.0
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", TPU_V5E),
+    ("cpu", "cpu", TPU_V5E),            # CPU runs plan for the target part
+    ("tpu", "TPU v99", None),           # an unknown TPU is not planned as v5e
+])
+def test_device_hardware_from_device_kind(monkeypatch, platform, kind, want):
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, kind)])
+    if want is None:
+        with pytest.raises(RuntimeError, match="TPU v99"):
+            device_hardware()
+    else:
+        assert device_hardware() is want
 
 
 def test_spec_for_prunes_non_divisible():

@@ -1,41 +1,22 @@
 """Distributed integration tests — each runs in a subprocess with virtual
 CPU devices (XLA device count is fixed at first jax import, so the main
 pytest process stays single-device)."""
-import os
-import subprocess
-import sys
-import textwrap
 
-import jax
+import functools
+
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from subproc import run_py as _run_py
 
-# Partially-manual shard_map (manual over one axis, GSPMD-auto over the
-# rest — the pipeline and compressed-DP paths) only lowers on current jax;
-# the 0.4.x line's XLA aborts on PartitionId / IsManualSubgroup.  See
-# repro/core/jax_compat.py for the API shims that cover everything else.
-requires_partial_auto_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map does not lower on jax<=0.4 "
-           "(XLA PartitionId/IsManualSubgroup)")
-
-
-def run_py(code: str, devices: int = 8, timeout: int = 540):
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=env, cwd=ROOT)
-    assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
-    return p.stdout
+# the snippets below want 8 devices unless they say otherwise
+run_py = functools.partial(_run_py, devices=8)
 
 
 def test_dp_matches_single_device_loss():
     """Data-parallel loss/grads == single-device (same params, same batch)."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         from repro.configs import get_config
         from repro.core.planner import compile_plan
         from repro.models.lm import build
@@ -46,7 +27,7 @@ def test_dp_matches_single_device_loss():
             np.random.default_rng(0).integers(0, cfg.vocab, (8, 64)),
             jnp.int32)}
         l_ref, _ = jax.jit(model.loss_fn)(params, batch)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         plan = compile_plan(model, mesh)
         with mesh:
             l_dist, _ = plan.jit_loss(batch)(params, batch)
@@ -55,18 +36,18 @@ def test_dp_matches_single_device_loss():
     """)
 
 
-@requires_partial_auto_shard_map
 def test_gpipe_loss_matches_reference():
     """Pipeline (2 stages × dp × tp) loss == non-pipelined loss."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         import repro as wh
         import repro.core.pipeline as pipe
         from repro.configs import get_config
         from repro.models.lm import build
         cfg = get_config("tinyllama-1.1b", smoke=True)
         model = build(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("stage", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("stage", "data", "model"))
         rules = wh.hybrid_rules(mesh)
         lfn, pspecs = pipe.make_pipeline_loss(model, mesh, rules,
                                               micro_batches=4)
@@ -85,10 +66,10 @@ def test_gpipe_loss_matches_reference():
     """)
 
 
-@requires_partial_auto_shard_map
 def test_gpipe_training_reduces_loss():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         import repro as wh
         import repro.core.pipeline as pipe
         from repro.configs import get_config
@@ -96,7 +77,7 @@ def test_gpipe_training_reduces_loss():
         from repro.optim import adamw
         cfg = get_config("tinyllama-1.1b", smoke=True)
         model = build(cfg)
-        mesh = jax.make_mesh((2, 2, 1), ("stage", "data", "model"))
+        mesh = make_mesh((2, 2, 1), ("stage", "data", "model"))
         rules = wh.hybrid_rules(mesh)
         opt = adamw(lr=1e-3)
         step = pipe.make_pipeline_train_step(model, mesh, rules, opt,
@@ -119,7 +100,6 @@ def test_gpipe_training_reduces_loss():
     """)
 
 
-@requires_partial_auto_shard_map
 def test_uneven_hetero_plan_pipeline_matches_reference():
     """The tentpole acceptance path: a mixed V100/P100 ClusterSpec →
     hetero planner emits an uneven latency-equalizing stage allocation →
@@ -172,11 +152,11 @@ def test_uneven_hetero_plan_pipeline_matches_reference():
     """)
 
 
-@requires_partial_auto_shard_map
 def test_compress_pod_training_step():
     """Cross-pod int8 error-feedback gradient reduction end-to-end."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         from repro.configs import get_config
         from repro.core.planner import compile_plan, mesh_for_strategy
         from repro.core.cost_model import StrategySpec
@@ -185,7 +165,7 @@ def test_compress_pod_training_step():
         from repro.optim import grad_compress
         cfg = get_config("tinyllama-1.1b", smoke=True)
         model = build(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         plan = compile_plan(model, mesh)
         opt = adamw(lr=1e-3)
         batch = {"tokens": jnp.asarray(np.random.default_rng(0).integers(
@@ -210,17 +190,18 @@ def test_expert_parallel_moe_matches_reference():
     """Tentpole acceptance: the nested replica{split[experts]} executor —
     moe_block_ep's shard_map with explicit all-to-all dispatch/combine
     bridges — equals the single-device moe_block to fp32 tolerance,
-    forward AND backward (runs on jax 0.4.x too: the shard_map is fully
-    manual over the expert axis)."""
+    forward AND backward (the shard_map is fully manual over the expert
+    axis)."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         from repro.models.moe import (MoECfg, init_moe, moe_block,
                                       moe_block_ep)
         cfg = MoECfg(d_model=32, n_experts=8, top_k=2, d_ff_expert=64,
                      n_shared=1)
         params = init_moe(jax.random.key(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.key(1), (8, 16, 32), jnp.float32)
-        mesh = jax.make_mesh((4,), ("expert",))
+        mesh = make_mesh((4,), ("expert",))
 
         y_ref, aux_ref = jax.jit(lambda p, x: moe_block(p, x, cfg))(params, x)
         with mesh:
@@ -251,10 +232,11 @@ def test_expert_parallel_moe_matches_reference():
 def test_expert_parallel_rejects_indivisible():
     run_py("""
         import jax, jax.numpy as jnp
+        from repro.core.sharding import make_mesh
         from repro.models.moe import MoECfg, init_moe, moe_block_ep
         cfg = MoECfg(d_model=16, n_experts=6, top_k=2, d_ff_expert=32)
         params = init_moe(jax.random.key(0), cfg, jnp.float32)
-        mesh = jax.make_mesh((4,), ("expert",))
+        mesh = make_mesh((4,), ("expert",))
         try:
             moe_block_ep(params, jnp.ones((8, 16, 16)), cfg, mesh)
         except ValueError as e:
@@ -269,6 +251,7 @@ def test_elastic_remesh_roundtrip(tmp_path):
     """Checkpoint on a 4×1 mesh, restore on 2×2 — values identical."""
     run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         from repro.ckpt.checkpoint import CheckpointManager
         from repro.configs import get_config
         from repro.core.planner import compile_plan
@@ -278,14 +261,14 @@ def test_elastic_remesh_roundtrip(tmp_path):
         cfg = get_config("qwen3-1.7b", smoke=True)
         model = build(cfg)
         opt = adamw(lr=1e-3)
-        mesh1 = jax.make_mesh((4, 1), ("data", "model"))
+        mesh1 = make_mesh((4, 1), ("data", "model"))
         plan1 = compile_plan(model, mesh1)
         with mesh1:
             params = plan1.init_params(jax.random.key(1))
             ost = opt.init(params)
         mgr = CheckpointManager({str(tmp_path)!r}, keep=2)
         mgr.save(7, {{"params": params, "opt": ost}}, extra={{"k": 1}})
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = make_mesh((2, 2), ("data", "model"))
         ctx = ElasticContext(model=model, optimizer=opt)
         step, plan2, p2, o2, extra = ctx.remesh(mgr, mesh2)
         assert step == 7 and extra["k"] == 1
@@ -325,13 +308,14 @@ def test_encdec_pipeline_loss_matches_reference():
     every supported jax (the grad path is gated below)."""
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.core.sharding import make_mesh
         import repro as wh
         import repro.core.pipeline as pipe
         from repro.configs import get_config
         from repro.models.lm import build
         cfg = get_config("seamless-m4t-medium", smoke=True)
         model = build(cfg)
-        mesh = jax.make_mesh((2, 1, 1), ("stage", "data", "model"))
+        mesh = make_mesh((2, 1, 1), ("stage", "data", "model"))
         rules = wh.hybrid_rules(mesh)
         lfn, pspecs = pipe.make_encdec_pipeline_loss(model, mesh, rules,
                                                      micro_batches=2)
@@ -352,12 +336,13 @@ def test_encdec_pipeline_loss_matches_reference():
 def test_encdec_pipeline_rejects_wrong_stage_count():
     run_py("""
         import jax
+        from repro.core.sharding import make_mesh
         import repro as wh
         import repro.core.pipeline as pipe
         from repro.configs import get_config
         from repro.models.lm import build
         model = build(get_config("seamless-m4t-medium", smoke=True))
-        mesh = jax.make_mesh((4, 1, 1), ("stage", "data", "model"))
+        mesh = make_mesh((4, 1, 1), ("stage", "data", "model"))
         try:
             pipe.make_encdec_pipeline_loss(model, mesh,
                                            wh.hybrid_rules(mesh),
@@ -392,7 +377,6 @@ def test_encdec_plan_routes_to_two_tower_engine():
     """, devices=2)
 
 
-@requires_partial_auto_shard_map
 def test_encdec_pipeline_training_reduces_loss():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np

@@ -1,21 +1,30 @@
 """End-to-end driver tests: train.py (with resume) and serve.py as CLIs."""
-import os
-import subprocess
-import sys
-
+import jax
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from repro.launch.compile_cache import DEFAULT_DIR, enable_compile_cache
+from subproc import run_child, run_cli
 
 
-def run_cli(args, devices: int = 2, timeout: int = 540):
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    p = subprocess.run([sys.executable, "-m"] + args, capture_output=True,
-                       text=True, timeout=timeout, env=env, cwd=ROOT)
-    assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
-    return p.stdout
+@pytest.mark.parametrize("env_dir", [None, "elsewhere/jax_cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """A set JAX_COMPILATION_CACHE_DIR is JAX's own to read: nothing is set
+    in code.  Unset, the cache goes to the checkout's fixed .jax_cache."""
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    got = enable_compile_cache()
+    if env_dir:
+        assert got == env_dir and updates == []
+    else:
+        assert DEFAULT_DIR.name == ".jax_cache"
+        assert (DEFAULT_DIR.parent / "chip_smoke.py").exists()
+        assert updates == [("jax_compilation_cache_dir", str(DEFAULT_DIR))]
+        assert got == str(DEFAULT_DIR)
 
 
 @pytest.mark.slow
@@ -94,14 +103,10 @@ def test_train_driver_vlm_rejects_pp(tmp_path):
     """The executable pipeline engine cannot stage the vision frontend:
     --pp on a vlm arch must fail loudly, and --auto must never route
     there (regression: auto used to pick pp=2 and crash in M-RoPE)."""
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=2",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    p = subprocess.run(
-        [sys.executable, "-m", "repro.launch.train", "--model",
-         "qwen2-vl-2b", "--smoke", "--pp", "2", "--steps", "1",
-         "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path)],
-        capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
+    p = run_child(
+        ["-m", "repro.launch.train", "--model", "qwen2-vl-2b", "--smoke",
+         "--pp", "2", "--steps", "1", "--batch", "2", "--seq", "32",
+         "--ckpt-dir", str(tmp_path)], devices=2, timeout=300)
     assert p.returncode != 0
     assert "does not apply to vlm" in p.stderr
 

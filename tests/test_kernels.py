@@ -25,6 +25,20 @@ from repro.kernels.xent.xent import xent_fwd
 from kernel_harness import check_fwd_bwd, rand, tol_for
 
 
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False),
+                                           ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, platform, want):
+    """Interpreted on the CPU, compiled on a TPU, and an error elsewhere
+    rather than an interpreter fallback that would hide the device."""
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            interpret_mode()
+    else:
+        assert interpret_mode() is want
+
+
 def _qkv(key, B, Sq, Sk, H, K, D, dtype):
     q = rand(key, (B, Sq, H, D), dtype)
     k = rand(jax.random.fold_in(key, 1), (B, Sk, K, D), dtype)
@@ -83,8 +97,7 @@ def test_flash_lse_matches_ref():
     mask = jnp.arange(128)[:, None] >= jnp.arange(128)[None, :]
     s = jnp.where(mask[None, None], s, -jnp.inf)
     ref = jax.scipy.special.logsumexp(s, axis=-1)          # (B, H, Sq)
-    got = jnp.moveaxis(lse.reshape(1, 128, 2), 2, 1)       # K*G == H here
-    np.testing.assert_allclose(got, ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, ref, atol=2e-5, rtol=2e-5)
 
 
 def test_flash_rejects_ragged_blocks():

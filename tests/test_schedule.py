@@ -253,9 +253,10 @@ def test_grad_accumulation_batch_guard_in_planner():
     import jax
     import jax.numpy as jnp
     from repro.core.planner import compile_plan
+    from repro.core.sharding import make_mesh
     from repro.optim.optimizer import adamw
     model = _f32_model(n_layers=2)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plan = compile_plan(model, mesh)
     fn = plan.train_step_fn(adamw(lr=1e-3), micro_batches=3)
     params = model.init(jax.random.key(0))

@@ -5,10 +5,6 @@ injector, cooperative loop stop, cluster shrinking, exactly-once data)
 run in-process; the end-to-end controller scenarios run in subprocesses
 with virtual CPU devices (XLA device count is fixed at first jax import).
 """
-import os
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import pytest
@@ -25,18 +21,7 @@ from repro.runtime.faults import (CrashStep, FaultInjector, SimClock,
                                   SlowHost)
 from repro.runtime.straggler import HostStragglerAggregator, StragglerMonitor
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def run_py(code: str, devices: int = 4, timeout: int = 540):
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=env, cwd=ROOT)
-    assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
-    return p.stdout
+from subproc import run_py
 
 
 # ---------------------------------------------------------------------------
@@ -376,6 +361,7 @@ def test_evict_remesh_onto_surviving_devices(tmp_path):
         from repro.configs import get_config
         from repro.core.cost_model import TPU_V5E
         from repro.core.planner import compile_plan
+        from repro.core.sharding import make_mesh
         from repro.models.lm import build, model_graph
         from repro.optim import adamw
         from repro.runtime.elastic import ElasticContext, HostTopology
@@ -383,7 +369,7 @@ def test_evict_remesh_onto_surviving_devices(tmp_path):
         model = build(cfg)
         opt = adamw(lr=1e-3)
         topo = HostTopology.uniform(2, 2, TPU_V5E)
-        mesh1 = jax.make_mesh((4,), ("data",))
+        mesh1 = make_mesh((4,), ("data",))
         plan1 = compile_plan(model, mesh1)
         with mesh1:
             params = plan1.init_params(jax.random.key(1))

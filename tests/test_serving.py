@@ -23,6 +23,7 @@ from repro.core.cost_model import (ClusterSpec, DeviceGroup, P100_16G,
                                    serving_page_budget)
 from repro.kernels.autotune import DEFAULT_TILES, autotune
 from repro.core.planner import compile_plan
+from repro.core.sharding import make_mesh
 from repro.serving.metrics import RequestTiming, ServeMetrics, percentile
 from repro.serving.paged_cache import (BlockTable, PageAllocator,
                                        PagedCacheConfig)
@@ -215,7 +216,7 @@ def served():
     cfg = get_config("tinyllama-1.1b", smoke=True)
     from repro.models.lm import build
     model = build(cfg)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     plan = compile_plan(model, mesh)
     with mesh:
         params = plan.init_params(jax.random.key(0))
@@ -362,8 +363,8 @@ def test_pallas_paged_decode_matches_ref(served):
     rng = jax.random.PRNGKey(0)
     ks = jax.random.split(rng, 5)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (P, ps, K, D), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (P, ps, K, D), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (P, K, ps, D), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (P, K, ps, D), jnp.float32)
     table = jnp.array([[2, 5, 0], [1, 3, 6]], jnp.int32)
     pos = jnp.array([6, 9], jnp.int32)
 
@@ -371,8 +372,8 @@ def test_pallas_paged_decode_matches_ref(served):
 
     # reference: gather pages logically, mask, softmax
     G = H // K
-    kg = k_pool[table].reshape(B, mp * ps, K, D)
-    vg = v_pool[table].reshape(B, mp * ps, K, D)
+    kg = jnp.swapaxes(k_pool[table], 2, 3).reshape(B, mp * ps, K, D)
+    vg = jnp.swapaxes(v_pool[table], 2, 3).reshape(B, mp * ps, K, D)
     qr = q.reshape(B, K, G, D) * (D ** -0.5)
     s = jnp.einsum("bkgd,bskd->bkgs", qr, kg)
     mask = jnp.arange(mp * ps)[None, :] <= pos[:, None]
