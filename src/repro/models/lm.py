@@ -385,21 +385,24 @@ class Model:
             return self._loss_encdec(params, batch)
         tokens = batch["tokens"]
         B, S = tokens.shape
-        x = self._embed_tokens(params, tokens, batch)
+        with jax.named_scope("embed"):
+            x = self._embed_tokens(params, tokens, batch)
         x, aux = tfm.apply_stack(params["blocks"], x, self._positions(B, S),
                                  self.stack)
-        x = layers.make_norm(cfg.norm)[2](params["final_norm"], x)
-        labels = tokens[:, 1:]
-        mask = jnp.ones_like(labels, jnp.float32)
-        if "loss_mask" in batch:
-            mask = mask * batch["loss_mask"][:, 1:]
-        if cfg.family == "vlm":
-            tgt_pos = jnp.arange(1, S)[None]
-            mask = mask * (tgt_pos >= cfg.frontend_len)
-        nll, zl, n = self._xent(
-            x[:, :-1], self._head_w(params).astype(cfg.adtype), labels, mask)
-        loss = nll / jnp.maximum(n, 1.0) + zl / jnp.maximum(n, 1.0) \
-            + aux["lb_loss"] + aux["z_loss"]
+        with jax.named_scope("loss_head"):
+            x = layers.make_norm(cfg.norm)[2](params["final_norm"], x)
+            labels = tokens[:, 1:]
+            mask = jnp.ones_like(labels, jnp.float32)
+            if "loss_mask" in batch:
+                mask = mask * batch["loss_mask"][:, 1:]
+            if cfg.family == "vlm":
+                tgt_pos = jnp.arange(1, S)[None]
+                mask = mask * (tgt_pos >= cfg.frontend_len)
+            nll, zl, n = self._xent(
+                x[:, :-1], self._head_w(params).astype(cfg.adtype), labels,
+                mask)
+            loss = nll / jnp.maximum(n, 1.0) + zl / jnp.maximum(n, 1.0) \
+                + aux["lb_loss"] + aux["z_loss"]
         metrics = {"nll": nll / jnp.maximum(n, 1.0), "tokens": n,
                    "moe_lb": aux["lb_loss"], "moe_z": aux["z_loss"]}
         return loss, metrics

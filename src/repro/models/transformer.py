@@ -104,6 +104,16 @@ def axes_block(cfg: BlockCfg) -> dict:
     return a
 
 
+def mixer_scope(cfg: BlockCfg) -> str:
+    """The ``jax.named_scope`` of a block's mixer sub-layer (DESIGN.md §13)."""
+    return "attention" if cfg.mixer == "attn" else "ssd"
+
+
+def mlp_scope(cfg: BlockCfg) -> str:
+    """The ``jax.named_scope`` of a block's MLP sub-layer (DESIGN.md §13)."""
+    return "moe" if cfg.mlp == "moe" else "mlp"
+
+
 def _zero_aux() -> dict:
     return {"lb_loss": jnp.zeros((), jnp.float32),
             "z_loss": jnp.zeros((), jnp.float32)}
@@ -115,27 +125,30 @@ def apply_block(params: dict, x: jax.Array, positions: jax.Array,
     _, _, norm = layers.make_norm(cfg.norm)
     aux = _zero_aux()
     kv = None
-    h = norm(params["norm1"], x)
-    if cfg.mixer == "attn":
-        out = attn_mod.attention(
-            params["attn"], h, positions, cfg.attn,
-            block_q=stack.attn_block_q, block_k=stack.attn_block_k,
-            wedge=stack.attn_wedge, return_kv=return_kv,
-            impl=stack.attn_impl, bwd_remat=stack.attn_bwd_remat)
-        if return_kv:
-            out, kv = out
-    else:
-        out = mamba2.ssd_block(params["ssd"], h, cfg.ssd,
-                               impl=stack.ssd_impl)
-    x = x + out
-    if cfg.mlp != "none":
-        h = norm(params["norm2"], x)
-        if cfg.mlp == "moe":
-            out, moe_aux = moe_mod.moe_block(params["moe"], h, cfg.moe)
-            aux = {"lb_loss": moe_aux["lb_loss"], "z_loss": moe_aux["z_loss"]}
+    with jax.named_scope(mixer_scope(cfg)):
+        h = norm(params["norm1"], x)
+        if cfg.mixer == "attn":
+            out = attn_mod.attention(
+                params["attn"], h, positions, cfg.attn,
+                block_q=stack.attn_block_q, block_k=stack.attn_block_k,
+                wedge=stack.attn_wedge, return_kv=return_kv,
+                impl=stack.attn_impl, bwd_remat=stack.attn_bwd_remat)
+            if return_kv:
+                out, kv = out
         else:
-            out = layers.mlp(params["mlp"], h, act=cfg.act)
+            out = mamba2.ssd_block(params["ssd"], h, cfg.ssd,
+                                   impl=stack.ssd_impl)
         x = x + out
+    if cfg.mlp != "none":
+        with jax.named_scope(mlp_scope(cfg)):
+            h = norm(params["norm2"], x)
+            if cfg.mlp == "moe":
+                out, moe_aux = moe_mod.moe_block(params["moe"], h, cfg.moe)
+                aux = {"lb_loss": moe_aux["lb_loss"],
+                       "z_loss": moe_aux["z_loss"]}
+            else:
+                out = layers.mlp(params["mlp"], h, act=cfg.act)
+            x = x + out
     x = constrain(x, ("batch", "seq", None))
     return x, aux, kv
 
@@ -144,28 +157,36 @@ def decode_block(params: dict, x: jax.Array, state: dict, pos: jax.Array,
                  cfg: BlockCfg):
     """x: (B, E) one token; state: kv cache or ssd state for this block."""
     _, _, norm = layers.make_norm(cfg.norm)
-    h = norm(params["norm1"], x[:, None, :])[:, 0]
-    if cfg.mixer == "attn":
-        if "k_sc" in state:              # int8 KV cache
-            out, k_new, v_new, ks, vs = attn_mod.decode_attention(
-                params["attn"], h, state["k"], state["v"], pos, cfg.attn,
-                k_sc=state["k_sc"], v_sc=state["v_sc"])
-            state = {"k": k_new, "v": v_new, "k_sc": ks, "v_sc": vs}
+    with jax.named_scope(mixer_scope(cfg)):
+        h = norm(params["norm1"], x[:, None, :])[:, 0]
+        if cfg.mixer == "attn":
+            if "k_sc" in state:              # int8 KV cache
+                out, k_new, v_new, ks, vs = attn_mod.decode_attention(
+                    params["attn"], h, state["k"], state["v"], pos, cfg.attn,
+                    k_sc=state["k_sc"], v_sc=state["v_sc"])
+                state = {"k": k_new, "v": v_new, "k_sc": ks, "v_sc": vs}
+            else:
+                out, k_new, v_new = attn_mod.decode_attention(
+                    params["attn"], h, state["k"], state["v"], pos, cfg.attn)
+                state = {"k": k_new, "v": v_new}
         else:
-            out, k_new, v_new = attn_mod.decode_attention(
-                params["attn"], h, state["k"], state["v"], pos, cfg.attn)
-            state = {"k": k_new, "v": v_new}
-    else:
-        out, state = mamba2.ssd_decode_step(params["ssd"], h, state, cfg.ssd)
-    x = x + out
+            out, state = mamba2.ssd_decode_step(params["ssd"], h, state,
+                                                cfg.ssd)
+        x = x + out
     if cfg.mlp != "none":
+        x = _decode_mlp(params, x, cfg, norm)
+    return x, state
+
+
+def _decode_mlp(params: dict, x: jax.Array, cfg: BlockCfg, norm):
+    """The MLP sub-layer of one decoded token: pre-norm, body, residual."""
+    with jax.named_scope(mlp_scope(cfg)):
         h = norm(params["norm2"], x[:, None, :])
         if cfg.mlp == "moe":
             out, _ = moe_mod.moe_block(params["moe"], h, cfg.moe)
         else:
             out = layers.mlp(params["mlp"], h, act=cfg.act)
-        x = x + out[:, 0]
-    return x, state
+        return x + out[:, 0]
 
 
 def init_block_state(cfg: BlockCfg, batch: int, max_len: int, dtype,
@@ -366,19 +387,15 @@ def paged_decode_block(params: dict, x: jax.Array, pools: dict,
                        cfg: BlockCfg, stack: StackCfg):
     """Paged twin of :func:`decode_block` for one attention block."""
     _, _, norm = layers.make_norm(cfg.norm)
-    h = norm(params["norm1"], x[:, None, :])[:, 0]
-    out, k_pool, v_pool = attn_mod.paged_decode_attention(
-        params["attn"], h, pools["k"], pools["v"], block_table, pos,
-        cfg.attn, impl=stack.attn_impl)
-    pools = {"k": k_pool, "v": v_pool}
-    x = x + out
+    with jax.named_scope(mixer_scope(cfg)):
+        h = norm(params["norm1"], x[:, None, :])[:, 0]
+        out, k_pool, v_pool = attn_mod.paged_decode_attention(
+            params["attn"], h, pools["k"], pools["v"], block_table, pos,
+            cfg.attn, impl=stack.attn_impl)
+        pools = {"k": k_pool, "v": v_pool}
+        x = x + out
     if cfg.mlp != "none":
-        h = norm(params["norm2"], x[:, None, :])
-        if cfg.mlp == "moe":
-            out, _ = moe_mod.moe_block(params["moe"], h, cfg.moe)
-        else:
-            out = layers.mlp(params["mlp"], h, act=cfg.act)
-        x = x + out[:, 0]
+        x = _decode_mlp(params, x, cfg, norm)
     return x, pools
 
 

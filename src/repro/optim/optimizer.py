@@ -71,6 +71,7 @@ def adamw(lr: Schedule | float = 3e-4, b1: float = 0.9, b2: float = 0.95,
         return {"mu": zeros,
                 "nu": _tmap(lambda p: jnp.zeros(p.shape, mdt), params)}
 
+    @jax.named_scope("optimizer")
     def apply(grads, state, params, step):
         if max_grad_norm:
             grads, _ = clip_by_global_norm(grads, max_grad_norm)
