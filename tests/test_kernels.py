@@ -18,7 +18,7 @@ from repro.kernels.quant.quant import dequantize, quantize
 from repro.kernels.quant.ref import dequant_ref, quant_ref
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.ssd.ssd import ssd_scan_pallas
-from repro.kernels.xent.ops import xent, xent_with_lse
+from repro.kernels.xent.ops import bwd_chunk, xent, xent_with_lse
 from repro.kernels.xent.ref import xent_ref
 from repro.kernels.xent.xent import xent_fwd
 
@@ -145,23 +145,74 @@ def test_xent_fwd_matches_ref(T, E, V, vocab, bt, bv):
     np.testing.assert_allclose(lse, lse_ref, atol=1e-4, rtol=1e-4)
 
 
-def test_xent_custom_vjp_matches_autodiff():
-    h, w, lab = _xent_inputs(jax.random.key(3), 128, 32, 512, 500)
+# backward vocab sweeps (bwd_chunk): 4 full chunks of 128; 2 of 384 and a
+# 128-column tail (896 has no lane-multiple divisor in [288, 576]); 1 chunk
+XENT_BWD_SHAPES = pytest.mark.parametrize("T,E,V,vocab", [
+    (128, 32, 512, 500),
+    (64, 192, 896, 880),
+    (128, 64, 128, 100),
+])
+
+
+@XENT_BWD_SHAPES
+def test_xent_custom_vjp_matches_autodiff(T, E, V, vocab):
+    h, w, lab = _xent_inputs(jax.random.key(3), T, E, V, vocab)
     check_fwd_bwd(
-        lambda h, w: xent(h, w, lab, 500, 64, 128, True),
-        lambda h, w: xent_ref(h, w, lab, vocab=500)[0],
+        lambda h, w: xent(h, w, lab, vocab, 64, 128, True),
+        lambda h, w: xent_ref(h, w, lab, vocab=vocab)[0],
         (h, w), diff_argnums=(0, 1), tol=tol_for(jnp.float32),
         msg="xent nll")
 
 
-def test_xent_with_lse_vjp_matches_autodiff():
+@XENT_BWD_SHAPES
+def test_xent_with_lse_vjp_matches_autodiff(T, E, V, vocab):
     """Both outputs carry cotangents — the z-loss gradient path."""
-    h, w, lab = _xent_inputs(jax.random.key(4), 128, 32, 512, 500)
+    h, w, lab = _xent_inputs(jax.random.key(4), T, E, V, vocab)
     check_fwd_bwd(
-        lambda h, w: xent_with_lse(h, w, lab, 500, 64, 128, True),
-        lambda h, w: xent_ref(h, w, lab, vocab=500),
+        lambda h, w: xent_with_lse(h, w, lab, vocab, 64, 128, True),
+        lambda h, w: xent_ref(h, w, lab, vocab=vocab),
         (h, w), diff_argnums=(0, 1), tol=tol_for(jnp.float32),
         msg="xent nll+lse")
+
+
+def test_xent_bwd_chunk_rule():
+    """The backward's vocab chunk comes from (T, E, V) alone: a lane-multiple
+    divisor of V in [3E/2, 3E], else 3E/2-wide chunks and one tail, capped
+    in bytes; the backward's loop runs that many chunks."""
+    # Qwen3-1.7B's padded head at batch 2 x 4096: 44 chunks of 3456
+    assert bwd_chunk(8192, 2048, 152064) == (3456, 44, 0)
+    # Mamba2's padded 50432 = 128·2·197 has no divisor in [3072, 6144]
+    assert bwd_chunk(8192, 2048, 50432) == (3072, 16, 1280)
+    # a long step: the (T, chunk) f32 tile stays within the byte ceiling
+    chunk, n, tail = bwd_chunk(65536, 2048, 152064)
+    assert 65536 * chunk * 4 <= 256 << 20
+    assert chunk % 128 == 0 and chunk < 2048
+    assert chunk * n + tail == 152064
+
+    T, E, V = 8192, 2048, 152064
+    args = (jax.ShapeDtypeStruct((T, E), jnp.bfloat16),
+            jax.ShapeDtypeStruct((E, V), jnp.bfloat16),
+            jax.ShapeDtypeStruct((T,), jnp.int32))
+
+    def grads(h, w, lab):
+        def loss(h, w):
+            nll, lse = xent_with_lse(h, w, lab, 151936, 128, 256, True)
+            return nll.sum() + lse.sum()
+        return jax.grad(loss, argnums=(0, 1))(h, w)
+
+    jaxpr = jax.make_jaxpr(grads)(*args).jaxpr
+    loops = [e.params["length"] for e in _all_eqns(jaxpr)
+             if e.primitive.name == "scan"]
+    assert loops == [44]
+
+
+def _all_eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _all_eqns(sub)
 
 
 def test_fused_xent_loss_head_matches_chunked():
