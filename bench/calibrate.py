@@ -12,7 +12,9 @@ left out planted in that reference (batch ≥ 2).  A state left unchanged
 reads 1 by construction.
 
 Every reading is compared with the float32 reference, as bench/run.py
-compares it.  One process holds the chip throughout.
+compares it, and judged under the cell's limits as bench/run.py judges
+it (``judged``): the program's readings must come out correct, the
+control's and each fault's not.  One process holds the chip throughout.
 """
 from __future__ import annotations
 
@@ -31,7 +33,7 @@ from bench import harness  # noqa: E402
 
 def train_readings(cell, config, devs, seeds, control_seeds) -> dict:
     from bench.traffic import train_steps as ts
-    ref = cell.reference()
+    ref = cell.reference
     prog = ts.build(cell, config, devs)
     prog_r = {}
     for s in seeds:
@@ -103,6 +105,20 @@ def propose_limits(out: dict) -> dict:
     return {"limits": limits, "readings": table}
 
 
+def judged(out: dict, limits: dict | None) -> dict:
+    """``correct`` for every measured reading under ``limits``, by
+    harness.judge as a run decides it, with the numbers over their limit."""
+    res = {}
+    for kind in ("program", "control", "half_batch"):
+        res[kind] = {}
+        for s, r in out.get(kind, {}).items():
+            ok, checks = harness.judge(r, limits)
+            res[kind][s] = {"correct": ok, "over": [
+                k for k, c in checks.items()
+                if c["limit"] is not None and not c["value"] <= c["limit"]]}
+    return res
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -126,6 +142,11 @@ def main(argv=None) -> int:
     out["seconds"] = time.perf_counter() - t0
     out["proposal"] = propose_limits(out)
     harness.log(f"proposed {out['proposal']}")
+    out["judged"] = judged(out, cell.params.get("limits"))
+    for kind, by_seed in out["judged"].items():
+        harness.log(f"{kind} under the cell's limits: " + ", ".join(
+            f"{s} correct={j['correct']} over={j['over']}"
+            for s, j in by_seed.items()))
     if args.write_limits:
         path = harness.BENCH / "workloads" / f"{cell.name}.json"
         params = harness.load_json(path)

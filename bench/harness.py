@@ -3,7 +3,10 @@ cache, the profiler window, the per-layer metric readers and the result
 line.  Everything a cell, a traffic mix, a configuration or a metric owns
 lives in files of its own, found here by the name in BENCHMARK.json:
 
-    bench/configs/<config>.json      sizes, source, reference module
+    bench/configs/<config>.json      sizes, source, reference name
+    bench/reference/<reference>.py   its plain float32 reference
+    bench/counts/models/<reference>.py  its model FLOPs per train step,
+                                     train_flops(config, batch, seq)
     bench/mixes/<traffic>.json       traffic parameters; "kind" names the
                                      generator bench/traffic/<kind>.py
     bench/workloads/<cell>.json      batch, kernels, limits of one cell
@@ -20,6 +23,7 @@ import math
 import os
 import sys
 import time
+import types
 from pathlib import Path
 
 import jax
@@ -47,6 +51,8 @@ class Cell:
     mix: dict                        # bench/mixes/<traffic>.json
     end_to_end: list                 # metric entries this cell reports
     per_layer: list
+    reference: types.ModuleType      # bench/reference/<reference>.py
+    counts: types.ModuleType         # bench/counts/models/<reference>.py
 
     @property
     def name(self) -> str:
@@ -56,9 +62,18 @@ class Cell:
     def chips(self) -> int:
         return int(self.spec["chips"])
 
-    def reference(self):
-        return importlib.import_module(
-            f"bench.reference.{self.config['reference']}")
+
+def model_module(package: str, config: dict) -> types.ModuleType:
+    """bench/<package>/<reference>.py for the ``reference`` name in the
+    configuration's file.  A file that is not there stops the run at once,
+    naming it, before anything is built."""
+    name = config["reference"]
+    rel = Path("bench", *package.split("."), f"{name}.py")
+    if not (ROOT / rel).is_file():
+        raise FileNotFoundError(
+            f"configuration {config['name']!r} names the reference "
+            f"{name!r}, but {rel} is not there")
+    return importlib.import_module(f"bench.{package}.{name}")
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -77,12 +92,23 @@ def load_cell(name: str) -> Cell:
 
 
 def make_cell(spec: dict, params: dict, end_to_end=(), per_layer=()) -> Cell:
-    """A cell from its BENCHMARK.json-style entry and its parameters."""
+    """A cell from its BENCHMARK.json-style entry and its parameters, with
+    its configuration's reference and count modules found."""
+    config = load_json(BENCH / "configs" / f"{spec['config']}.json")
     return Cell(
-        spec=spec, params=params,
-        config=load_json(BENCH / "configs" / f"{spec['config']}.json"),
+        spec=spec, params=params, config=config,
         mix=load_json(BENCH / "mixes" / f"{spec['traffic']}.json"),
-        end_to_end=list(end_to_end), per_layer=list(per_layer))
+        end_to_end=list(end_to_end), per_layer=list(per_layer),
+        reference=model_module("reference", config),
+        counts=model_module("counts.models", config))
+
+
+def cells_of_kind(kind: str) -> list:
+    """The names of BENCHMARK.json's cells whose traffic mix has ``kind``."""
+    bench = load_json(ROOT / "BENCHMARK.json")
+    return [w["name"] for w in bench["workloads"]
+            if load_json(BENCH / "mixes" / f"{w['traffic']}.json")["kind"]
+            == kind]
 
 
 def rehearsal_config(config: dict) -> dict:

@@ -1,7 +1,8 @@
 """train_mfu: model FLOP/s of the train steps in the traced window over
 the chips' bf16 peak.  Steps are counted from the device's own record of
 the step program's executions (``XLA Modules``), each by the share of it
-that lies in the window; FLOPs per step come from bench/counts/model.py.
+that lies in the window; FLOPs per step come from the configuration's
+bench/counts/models/<reference>.py.
 """
 from bench import trace as tr
 

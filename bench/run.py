@@ -37,7 +37,6 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import harness, trace as tr  # noqa: E402
-from bench.counts.model import TRAIN_FLOPS  # noqa: E402
 
 
 def main(argv=None, *, fault: str | None = None) -> int:
@@ -74,8 +73,7 @@ def main(argv=None, *, fault: str | None = None) -> int:
            "seconds": args.seconds, "devices": devs, "t_start": T_START,
            "trace_dir": trace_dir, "compiles": counter, "fault": fault,
            "trace_seconds": min(args.seconds,
-                                float(cell.mix.get("trace_seconds", 3))),
-           "train_flops": TRAIN_FLOPS.get(config["reference"])}
+                                float(cell.mix.get("trace_seconds", 3)))}
     res = gen.run(ctx)
 
     device, breakdown = res["device"], None

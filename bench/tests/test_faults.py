@@ -12,12 +12,14 @@ from pathlib import Path
 
 import pytest
 
+from bench import harness
+
 ROOT = Path(__file__).resolve().parents[2]
 
-CASES = [
-    ("qwen3-1.7b-d7.train-4k", "frozen"),
-    ("qwen3-1.7b-d7.train-4k", "half_batch"),
-]
+# every training cell of BENCHMARK.json, each with every fault of the step
+TRAIN_FAULTS = ("frozen", "half_batch")
+CASES = [(cell, fault) for cell in harness.cells_of_kind("train_steps")
+         for fault in TRAIN_FAULTS]
 
 
 def rehearse(cell: str, fault: str | None, seed: int = 7) -> dict:

@@ -91,7 +91,7 @@ def build(cell: harness.Cell, config: dict, devs, *, fault=None) -> Program:
     cfg = lm_config(config, p)
     mesh = make_mesh((len(devs),), ("data",), devices=devs)
     plan = compile_plan(build_model(cfg), mesh)
-    ref = cell.reference()
+    ref = cell.reference
     weights.check_layout(ref.shapes(config), plan.param_shapes)
     opt = optimizer(cell.mix)
     B, S = int(p["batch"]), int(cell.mix["seq_len"])
@@ -242,7 +242,7 @@ def reference_readings(cell: harness.Cell, config: dict, seed: int, devs, *,
     """The reference's readings on the same weights and rows, on the first
     chip.  ``fault="half_batch"`` plants that fault of the program in the
     reference put in its place."""
-    ref = cell.reference()
+    ref = cell.reference
     B, S = int(cell.params["batch"]), int(cell.mix["seq_len"])
     rows = [tok.rows(seed, TRAIN_STREAM, i, B, S, config["vocab_size"])
             for i in range(CHECK_STEPS)]
@@ -306,7 +306,9 @@ def worst_leaves(prog: dict, ref: dict) -> dict:
 def run(ctx: dict) -> dict:
     cell: harness.Cell = ctx["cell"]
     config, seed, devs = ctx["config"], ctx["seed"], ctx["devices"]
-    ref = cell.reference()
+    B, S = int(cell.params["batch"]), int(cell.mix["seq_len"])
+    step_flops = cell.counts.train_flops(config, B, S)
+    ref = cell.reference
     prog = build(cell, config, devs, fault=ctx.get("fault"))
     initial_state(prog, config, ref, seed)
     harness.log(f"built and initialised at "
@@ -328,9 +330,11 @@ def run(ctx: dict) -> dict:
     w = window(prog, seed, ctx["seconds"], tw)
     ctx["compiles"].armed = False
     device = harness.device_info(devs)
+    device["window_compiles"] = ctx["compiles"].compiles
+    device["window_cache_loads"] = ctx["compiles"].cache_loads
     harness.log(f"window: {w['steps']} steps in {w['seconds']:.3f}s; "
-                f"compiles {ctx['compiles'].compiles}, cache loads "
-                f"{ctx['compiles'].cache_loads} inside it")
+                f"compiles {device['window_compiles']}, cache loads "
+                f"{device['window_cache_loads']} inside it")
     prog.params = prog.opt_state = None
     del prog
     gc.collect()
@@ -344,7 +348,7 @@ def run(ctx: dict) -> dict:
     ok, checks = harness.judge(values, cell.params.get("limits"))
     checks["window_losses_finite"] = {"value": float(not finite),
                                       "limit": 0.0}
-    tokens = w["steps"] * int(cell.params["batch"]) * int(cell.mix["seq_len"])
+    tokens = w["steps"] * B * S
     return {
         "correct": bool(ok) and finite,
         "attempted": w["steps"],
@@ -354,9 +358,5 @@ def run(ctx: dict) -> dict:
         "device": device,
         "checks": checks,
         "hlo": hlo,
-        "layer_ctx": {"step_flops": ctx["train_flops"](
-                          config, int(cell.params["batch"]),
-                          int(cell.mix["seq_len"])),
-                      "batch": int(cell.params["batch"]),
-                      "seq": int(cell.mix["seq_len"])},
+        "layer_ctx": {"step_flops": step_flops, "batch": B, "seq": S},
     }
